@@ -32,6 +32,8 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     help="comma-separated benchmark names")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     n_evals = 30 if args.quick else 100
     only = set(args.only.split(",")) if args.only else None
 

@@ -431,8 +431,10 @@ class Broker(SchedulingPolicy):
             for i in sorted(self._queues):
                 if i != self._surrogate_id:
                     items.extend(self._queues[i].pending())
-            for req, attempt in items:
-                self._note_enqueue(req, attempt)
+            # one batched predictor pass, not one predict per task
+            costs = self.costs([req for req, _ in items])
+            for (req, attempt), c in zip(items, costs):
+                self._note_enqueue(req, attempt, cost=c)
         return max(self._cost_total, 0.0)
 
     def _note_enqueue(self, req, attempt: int,

@@ -327,6 +327,7 @@ class Executor:
         self._lock = threading.RLock()
         self._cv = threading.Condition(self._lock)
         self._waiting: List[Tuple[EvalRequest, int]] = []   # unmet deps
+        self._swallowed: Dict[str, Tuple[int, str]] = {}     # see _swallow
         # task_id -> (request, worker, start time, attempt number)
         self._running: Dict[str, Tuple[EvalRequest, Worker, float, int]] = {}
         # second in-flight copy of a speculatively re-executed task
@@ -459,12 +460,12 @@ class Executor:
                             self.registry.observe(
                                 "predictor_abs_residual",
                                 abs(pred - res.compute_t))
-                    except Exception:  # noqa: BLE001 — best-effort
-                        pass
+                    except Exception as e:  # noqa: BLE001 — counted
+                        self._swallow("predictor.predict", e)
                 try:
                     self.predictor.observe(req, res.compute_t)
-                except Exception:  # noqa: BLE001 — prediction is best-effort
-                    pass
+                except Exception as e:  # noqa: BLE001 — counted
+                    self._swallow("predictor.observe", e)
             sur = self._surrogate()
             if sur is not None:
                 # a real run is ground truth for the QoI surrogate too:
@@ -472,8 +473,8 @@ class Executor:
                 try:
                     sur.observe(req.parameters, res.value,
                                 model_name=req.model_name)
-                except Exception:  # noqa: BLE001 — enrichment is best-effort
-                    pass
+                except Exception as e:  # noqa: BLE001 — counted
+                    self._swallow("surrogate.observe", e)
         with self._cv:
             # the completing ATTEMPT picks its own slot: a hedged task
             # has two in-flight copies keyed by the same task_id, and
@@ -841,8 +842,20 @@ class Executor:
         if self.on_result is not None:
             try:
                 self.on_result(req, res)
-            except Exception:  # noqa: BLE001
-                pass
+            except Exception as e:  # noqa: BLE001 — counted
+                self._swallow("on_result", e)
+
+    def _swallow(self, site: str, exc: Exception) -> None:
+        """Absorb an error from a best-effort hook (predictor, surrogate
+        enrichment, result accounting) so the task it rode on still
+        completes — but never silently: every one is counted per site in
+        `metrics()["swallowed_errors"]` (and in the registry, when one is
+        attached), with the newest message kept per site."""
+        with self._lock:
+            n, _ = self._swallowed.get(site, (0, ""))
+            self._swallowed[site] = (n + 1, f"{type(exc).__name__}: {exc}")
+            if self.registry is not None:
+                self.registry.inc("swallowed_errors", labels={"site": site})
 
     def _monitor_loop(self):
         while not self._stopping:
@@ -996,6 +1009,8 @@ class Executor:
                     self.tracer.events())["totals"]
             return {
                 "offload": offload,
+                # site -> (count, newest message); see `_swallow`
+                "swallowed_errors": dict(self._swallowed),
                 "stepper_events": (list(self._stepper.events)
                                    if self._stepper is not None else []),
                 "overhead_attribution": attribution,

@@ -30,11 +30,13 @@ NEG_INF = -1e30
 def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *, sq, skv, block_q, block_kv,
                  causal, scale):
     qi = pl.program_id(1)
-    q = q_ref[...][0].astype(jnp.float32) * scale            # [bq, dh]
-    bq, dh = q.shape
+    q = q_ref[0].astype(jnp.float32) * scale                 # [bq, dh]
+    bq = q.shape[0]
     dv = v_ref.shape[-1]
 
-    q_pos = qi * block_q + jax.lax.iota(jnp.int32, bq)        # global q rows
+    # global q rows, kept 2-D: Mosaic lays out no 1-D index vectors
+    q_pos = qi * block_q + jax.lax.broadcasted_iota(
+        jnp.int32, (bq, block_kv), 0)
     nkv = pl.cdiv(skv, block_kv)
     if causal:
         # highest kv block this q block can see (diag offset skv - sq)
@@ -46,32 +48,32 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *, sq, skv, block_q, block_kv,
 
     def body(ki, carry):
         m, l, acc = carry
-        k = pl.load(k_ref, (0, pl.dslice(ki * block_kv, block_kv),
-                            slice(None))).astype(jnp.float32)
-        v = pl.load(v_ref, (0, pl.dslice(ki * block_kv, block_kv),
-                            slice(None))).astype(jnp.float32)
+        start = pl.multiple_of(ki * block_kv, block_kv)
+        k = k_ref[0, pl.ds(start, block_kv), :].astype(jnp.float32)
+        v = v_ref[0, pl.ds(start, block_kv), :].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        k_pos = ki * block_kv + jax.lax.iota(jnp.int32, block_kv)
-        valid = (k_pos < skv)[None, :]
+        k_pos = ki * block_kv + jax.lax.broadcasted_iota(
+            jnp.int32, (bq, block_kv), 1)
+        valid = k_pos < skv
         if causal:
-            valid = valid & (k_pos[None, :] <= q_pos[:, None] + (skv - sq))
+            valid = valid & (k_pos <= q_pos + (skv - sq))
         s = jnp.where(valid, s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         corr = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new[:, None]) * valid
-        l_new = l * corr + jnp.sum(p, axis=-1)
-        acc_new = acc * corr[:, None] + jax.lax.dot_general(
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        l_new = l * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_new = acc * corr + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         return m_new, l_new, acc_new
 
-    init = (jnp.full((bq,), NEG_INF, jnp.float32),
-            jnp.zeros((bq,), jnp.float32),
+    init = (jnp.full((bq, 1), NEG_INF, jnp.float32),
+            jnp.zeros((bq, 1), jnp.float32),
             jnp.zeros((bq, dv), jnp.float32))
     m, l, acc = jax.lax.fori_loop(0, nkv_used, body, init)
-    out = acc / jnp.maximum(l, 1e-30)[:, None]
-    o_ref[...] = out[None].astype(o_ref.dtype)
+    out = acc / jnp.maximum(l, 1e-30)
+    o_ref[0] = out.astype(o_ref.dtype)
 
 
 def _pad_to(x, axis, mult):

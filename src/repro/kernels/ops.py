@@ -30,11 +30,7 @@ def set_default_impl(impl: str) -> None:
 def _resolve(impl: Optional[str]) -> str:
     impl = impl or _DEFAULT_IMPL
     if impl == "auto":
-        try:
-            on_tpu = jax.default_backend() == "tpu"
-        except Exception:
-            on_tpu = False
-        return "pallas" if on_tpu else "xla"
+        return "pallas" if jax.default_backend() == "tpu" else "xla"
     return impl
 
 

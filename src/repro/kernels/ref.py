@@ -358,13 +358,18 @@ def mamba2_ssd_chunked(x, dt, a, b_in, c_in, d,
 # ==========================================================================
 # GP kernel matrix (RBF / Matern-5/2)
 # ==========================================================================
+# f32 matmuls: TPU's default one-pass bf16 would swamp the posterior
+# variance (a difference var - ||L^-1 ks||^2); CPU results are unchanged
+_HI = jax.lax.Precision.HIGHEST
+
+
 def gp_kernel_matrix(x1: jax.Array, x2: jax.Array, lengthscale: jax.Array,
                      variance: jax.Array, kind: str = "rbf") -> jax.Array:
     """x1: [N,D]; x2: [M,D]; ARD lengthscale: [D] -> [N,M] (f32)."""
     x1s = x1.astype(jnp.float32) / lengthscale.astype(jnp.float32)
     x2s = x2.astype(jnp.float32) / lengthscale.astype(jnp.float32)
     d2 = (jnp.sum(x1s ** 2, -1)[:, None] + jnp.sum(x2s ** 2, -1)[None, :]
-          - 2.0 * x1s @ x2s.T)
+          - 2.0 * jnp.matmul(x1s, x2s.T, precision=_HI))
     d2 = jnp.maximum(d2, 0.0)
     if kind == "rbf":
         k = jnp.exp(-0.5 * d2)
@@ -388,8 +393,8 @@ def gp_predict(x_train: jax.Array, x_star: jax.Array, lengthscale: jax.Array,
     output scale.
     """
     ks = gp_kernel_matrix(x_train, x_star, lengthscale, variance, kind)
-    mean = ks.T @ alpha
-    v = linv @ ks
+    mean = jnp.matmul(ks.T, alpha, precision=_HI)
+    v = jnp.matmul(linv, ks, precision=_HI)
     qf = jnp.sum(v * v, axis=0)
     return mean, qf
 

@@ -99,11 +99,9 @@ class _EngineBase:
 
     def warm(self) -> None:
         """Pre-compile the single-row predict bucket (push-time trust
-        checks run under dispatch locks — never stall them on XLA)."""
-        try:
-            self.predict_batch(np.asarray(self.x[:1], np.float32))
-        except Exception:  # noqa: BLE001 — warmup is best-effort
-            pass
+        checks run under dispatch locks — never stall them on XLA).  A
+        compile error raises here, where it is cheapest to see."""
+        self.predict_batch(np.asarray(self.x[:1], np.float32))
 
 
 # ===========================================================================
@@ -668,21 +666,19 @@ class PartitionedEngine(_EngineBase):
         return self._stack
 
     def _maybe_shard(self, arrs):
-        """Best-effort expert-axis sharding across devices (XLA path);
-        silently unsharded when the mesh does not fit."""
+        """Expert-axis sharding across devices; unsharded on one device or
+        when the expert count does not divide the device count.  Any other
+        sharding error raises."""
         if not self.shard:
             return arrs
-        try:
-            devs = jax.devices()
-            if len(devs) < 2 or len(self.experts) % len(devs):
-                return arrs
-            from jax.sharding import Mesh, NamedSharding
-            from jax.sharding import PartitionSpec as P
-            mesh = Mesh(np.array(devs), ("expert",))
-            sharding = NamedSharding(mesh, P("expert"))
-            return tuple(jax.device_put(a, sharding) for a in arrs)
-        except Exception:  # noqa: BLE001
+        devs = jax.devices()
+        if len(devs) < 2 or len(self.experts) % len(devs):
             return arrs
+        from jax.sharding import Mesh, NamedSharding
+        from jax.sharding import PartitionSpec as P
+        mesh = Mesh(np.array(devs), ("expert",))
+        sharding = NamedSharding(mesh, P("expert"))
+        return tuple(jax.device_put(a, sharding) for a in arrs)
 
     def predict_batch(self, x_star) -> Tuple[jax.Array, jax.Array]:
         """Route, group by expert, answer every group in fused stacked

@@ -143,6 +143,9 @@ def nlml(tree, x, y, kind: str = "rbf") -> jax.Array:
     y2 = y if y.ndim == 2 else y[:, None]
     yc = y2 - jnp.mean(y2, axis=0, keepdims=True)
     n, m = yc.shape
+    # K comes from `kops.gp_kernel_matrix` like every other covariance: on
+    # TPU the Pallas kernel runs forward and its custom VJP differentiates
+    # the jnp reference expression (`kernels/gp_kernel.py`)
     chol = _chol_factor(params, x, kind)
     alpha = jax.scipy.linalg.cho_solve((chol, True), yc)
     logdet = 2.0 * jnp.sum(jnp.log(jnp.diagonal(chol)))
@@ -202,7 +205,8 @@ def fit(x: jax.Array, y: jax.Array, kind: str = "rbf", steps: int = 200,
 def _predict(params_tree, x_train, y_mean, y_std, chol, alpha, x_star, kind):
     params = GPParams.from_tree(params_tree)
     ks = _kernel(params, x_train, x_star, kind)                 # [N, S]
-    mean = y_mean[None] + (ks.T @ alpha) * y_std[None]          # [S, M]
+    mean = y_mean[None] + jnp.matmul(
+        ks.T, alpha, precision=jax.lax.Precision.HIGHEST) * y_std[None]
     v = jax.scipy.linalg.solve_triangular(chol, ks, lower=True)  # [N, S]
     prior = jnp.exp(params.log_variance)
     var = jnp.maximum(prior - jnp.sum(v * v, axis=0), 1e-12)    # [S]

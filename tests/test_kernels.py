@@ -225,6 +225,53 @@ def test_gp_kernel_pallas_vs_oracle(n, m, d, kind):
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
 
 
+def _gp_predict_operands(key, n, s, d, e=None):
+    """Training/query points and a real posterior's alpha and L^-1."""
+    ks = jax.random.split(key, 4)
+    lead = () if e is None else (e,)
+    xt = jax.random.normal(ks[0], lead + (n, d))
+    xs = jax.random.normal(ks[1], lead + (s, d))
+    ls = jnp.exp(jax.random.normal(ks[2], (d,)) * 0.2) * 1.5
+    var = jnp.float32(1.7)
+
+    def factor(x):
+        kxx = ref.gp_kernel_matrix(x, x, ls, var) + 0.1 * jnp.eye(n)
+        chol = jnp.linalg.cholesky(kxx)
+        return jax.scipy.linalg.solve_triangular(chol, jnp.eye(n),
+                                                 lower=True)
+
+    linv = factor(xt) if e is None else jax.vmap(factor)(xt)
+    alpha = jax.random.normal(ks[3], lead + (n, 2))
+    return xt, xs, ls, var, alpha, linv
+
+
+@pytest.mark.parametrize("n,s,block_n", [(100, 57, 512), (40, 20, 16),
+                                         (130, 33, 64), (5, 3, 512)])
+@pytest.mark.parametrize("kind", ["rbf", "matern52"])
+def test_gp_predict_pallas_vs_oracle(n, s, block_n, kind):
+    """The training-dimension tiling (several row/column blocks of L^-1,
+    a padded last block) reproduces the one-shot reference."""
+    xt, xs, ls, var, alpha, linv = _gp_predict_operands(
+        jax.random.PRNGKey(10), n, s, 7)
+    got_m, got_q = gp_kernel.gp_predict(xt, xs, ls, var, alpha, linv, kind,
+                                        block_s=32, block_n=block_n,
+                                        interpret=True)
+    want_m, want_q = ref.gp_predict(xt, xs, ls, var, alpha, linv, kind)
+    np.testing.assert_allclose(got_m, want_m, atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(got_q, want_q, atol=2e-5, rtol=2e-5)
+
+
+def test_gp_predict_experts_pallas_vs_oracle():
+    xt, xs, ls, var, alpha, linv = _gp_predict_operands(
+        jax.random.PRNGKey(11), 48, 20, 3, e=3)
+    got_m, got_q = gp_kernel.gp_predict_experts(
+        xt, xs, ls, var, alpha, linv, block_s=16, block_n=16,
+        interpret=True)
+    want_m, want_q = ref.gp_predict_experts(xt, xs, ls, var, alpha, linv)
+    np.testing.assert_allclose(got_m, want_m, atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(got_q, want_q, atol=2e-5, rtol=2e-5)
+
+
 @settings(max_examples=10, deadline=None)
 @given(n=st.integers(2, 40), d=st.integers(1, 8), seed=st.integers(0, 999))
 def test_gp_kernel_properties(n, d, seed):

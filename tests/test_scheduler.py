@@ -184,6 +184,27 @@ def test_executor_retry_and_fail():
         assert bad.status == "failed"
 
 
+def test_executor_counts_swallowed_hook_errors():
+    """A failing predictor does not fail the task it rode on, and does not
+    vanish either: each swallowed error is counted per site, in the
+    executor's metrics and in the registry."""
+    from repro.obs import MetricsRegistry
+    from repro.sched.predictor import QuantileEstimator
+
+    class Broken(QuantileEstimator):
+        def observe(self, req, compute_t):
+            raise RuntimeError("device lost")
+
+    reg = MetricsRegistry()
+    with Executor({"toy": _toy_factory}, n_workers=2, predictor=Broken(),
+                  metrics_registry=reg) as ex:
+        res = ex.run_all([EvalRequest("toy", [[i]]) for i in range(5)])
+        swallowed = ex.metrics()["swallowed_errors"]
+    assert all(r.status == "ok" for r in res)
+    assert swallowed["predictor.observe"] == (5, "RuntimeError: device lost")
+    assert reg.counters["swallowed_errors{site=predictor.observe}"] == 5
+
+
 def test_executor_worker_death_requeues():
     def slow():
         return LambdaModel("s", lambda p, c: (time.sleep(0.2), [[1.0]])[1],
