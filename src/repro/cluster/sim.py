@@ -543,13 +543,6 @@ def simulate_cluster(spec: BackendSpec, trace: List[TraceTask], *,
             # costs at this theta
             if broker.predictor is not None and \
                     not req.config.get("_surrogate"):
-                if registry is not None:
-                    # pre-observe residual: |predicted - actual| before
-                    # this completion sharpens the predictor
-                    pred = broker.predictor.predict(req)
-                    if pred is not None:
-                        registry.observe("predictor_abs_residual",
-                                         abs(pred - w.compute))
                 broker.predictor.observe(req, w.compute)
             if not req.config.get("_surrogate"):
                 real_done.append((req.model_name, w.compute))

@@ -97,6 +97,20 @@ class Worker(threading.Thread):
             self.servers[name] = server
         return server, init_t
 
+    def _finish(self, req: EvalRequest, attempt: int, value: Any,
+                wname: str, dispatch_t: float, compute_t: float,
+                init_t: float) -> None:
+        """Stamp the attempt's result and hand it to the completion path."""
+        status = "ok"
+        if req.time_limit and compute_t > req.time_limit:
+            status = "timeout"
+        self.pool._complete(req, EvalResult(
+            task_id=req.task_id, value=value, status=status,
+            worker=wname, attempts=attempt,
+            submit_t=req.submit_t, dispatch_t=dispatch_t,
+            start_t=dispatch_t, end_t=self.pool._clock(),
+            compute_t=compute_t, init_t=init_t))
+
     def run(self):
         while self.alive:
             try:
@@ -137,16 +151,17 @@ class Worker(threading.Thread):
                     compute_t = self.pool._clock() - t0
                     server.n_evals += 1
                     wname = self.name
-                status = "ok"
-                if req.time_limit and compute_t > req.time_limit:
-                    status = "timeout"
-                res = EvalResult(
-                    task_id=req.task_id, value=value, status=status,
-                    worker=wname, attempts=attempt,
-                    submit_t=req.submit_t, dispatch_t=dispatch_t,
-                    start_t=dispatch_t, end_t=self.pool._clock(),
-                    compute_t=compute_t, init_t=init_t)
-                self.pool._complete(req, res)
+                tracer = self.pool.tracer
+                if tracer is None:
+                    self._finish(req, attempt, value, wname, dispatch_t,
+                                 compute_t, init_t)
+                else:
+                    # opened before the result's end_t is read, so that
+                    # task.run and task.complete meet with no gap
+                    with tracer.scope("task.complete", task=req.task_id,
+                                      offloaded=surrogate is not None):
+                        self._finish(req, attempt, value, wname,
+                                     dispatch_t, compute_t, init_t)
             except Exception as e:  # noqa: BLE001 — any task failure requeues
                 if surrogate_failed:
                     # a broken SURROGATE must not fail the task: PIN the
@@ -281,6 +296,8 @@ class Executor:
         # policy instance arrived with its own, that binding wins and any
         # `predictor=` kwarg is superseded (no split-brain feedback loop)
         self.predictor = self.policy.predictor
+        if tracer is not None and hasattr(self.predictor, "tracer"):
+            self.predictor.tracer = tracer
         self.allocation_s = allocation_s
         self._cluster_mode = isinstance(self.policy, Broker)
         if tracer is not None:
@@ -324,8 +341,15 @@ class Executor:
             # shared allocator instance behaves identically on both paths
             self.autoalloc.worker_cap = max_workers
 
-        self._lock = threading.RLock()
+        if tracer is None:
+            self._lock = threading.RLock()
+        else:                                  # lock.wait / lock.held spans
+            from repro.obs.trace import TracedLock
+            self._lock = TracedLock(tracer)
         self._cv = threading.Condition(self._lock)
+        # task id -> when _complete stored its result (tracing only): the
+        # start of the `task.handoff` span that `result` closes
+        self._stored_t: Dict[str, float] = {}
         self._waiting: List[Tuple[EvalRequest, int]] = []   # unmet deps
         self._swallowed: Dict[str, Tuple[int, str]] = {}     # see _swallow
         # task_id -> (request, worker, start time, attempt number)
@@ -451,17 +475,6 @@ class Executor:
             # of GP predict must not teach the runtime predictor what the
             # REAL model costs at this theta.
             if self.predictor is not None:
-                if self.registry is not None:
-                    # residual BEFORE observe: the prediction this run's
-                    # dispatch actually used, not the sharpened one
-                    try:
-                        pred = self.predictor.predict(req)
-                        if pred is not None:
-                            self.registry.observe(
-                                "predictor_abs_residual",
-                                abs(pred - res.compute_t))
-                    except Exception as e:  # noqa: BLE001 — counted
-                        self._swallow("predictor.predict", e)
                 try:
                     self.predictor.observe(req, res.compute_t)
                 except Exception as e:  # noqa: BLE001 — counted
@@ -471,8 +484,14 @@ class Executor:
                 # a real run is ground truth for the QoI surrogate too:
                 # conditioning on it widens the trusted region
                 try:
-                    sur.observe(req.parameters, res.value,
-                                model_name=req.model_name)
+                    if self.tracer is None:
+                        sur.observe(req.parameters, res.value,
+                                    model_name=req.model_name)
+                    else:
+                        with self.tracer.scope("surrogate.observe",
+                                               task=req.task_id):
+                            sur.observe(req.parameters, res.value,
+                                        model_name=req.model_name)
                 except Exception as e:  # noqa: BLE001 — counted
                     self._swallow("surrogate.observe", e)
         with self._cv:
@@ -504,6 +523,8 @@ class Executor:
             if prev is None or prev.status not in ("ok", "failed",
                                                    "quarantined"):
                 self._results[req.task_id] = res
+                if self.tracer is not None:
+                    self._stored_t[req.task_id] = self._clock()
                 # first-completion-wins: any OTHER copy of this task
                 # still in flight lost the race — cancel it, billing the
                 # partial work where it ran
@@ -671,7 +692,13 @@ class Executor:
                 if left <= 0:
                     raise TimeoutError(task_id)
                 self._cv.wait(min(left, 0.05))
-            return self._results[task_id]
+            res = self._results[task_id]
+            stored = (self._stored_t.pop(task_id, None)
+                      if self.tracer is not None else None)
+        if stored is not None:
+            self.tracer.host_span("task.handoff", stored, self._clock(),
+                                  {"task": task_id})
+        return res
 
     def run_all(self, reqs: Sequence[EvalRequest], timeout: float = 600.0
                 ) -> List[EvalResult]:

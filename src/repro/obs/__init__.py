@@ -8,6 +8,14 @@ produces identical span sequences from `simulate_cluster` and the live
 ``tracer=None`` / ``registry=None`` defaults keep the hot paths free of
 even the tuple-append cost.
 
+Given a tracer, the live service also records where its host time goes:
+`Tracer.scope` spans on a ``host`` track per thread (``svc.submit``,
+``predictor.*``, ``offload.trust``, ``task.complete``,
+``surrogate.observe``, ``task.handoff``) and the dispatch lock's
+``lock.wait`` / ``lock.held`` (`TracedLock`), each mirrored into the JAX
+profiler's host plane under the same name.  `span_sequence` leaves them
+out, so sim/live parity compares scheduling events only.
+
 On top of the recording layer sit the consumers that close the
 sim-to-reality gap: `repro.obs.calib` fits per-phase overhead
 distributions from a trace into a drop-in `CalibratedBackendSpec` and
@@ -26,12 +34,13 @@ from repro.obs.calib import (SACCT_DEFAULT_FIELDS, CalibratedBackendSpec,
 from repro.obs.registry import DEFAULT_EDGES, Histogram, MetricsRegistry
 from repro.obs.replay import (ReplayBackendSpec, TraceReplay,
                               replay_cluster)
-from repro.obs.trace import (RingBuffer, TraceEvent, Tracer, read_jsonl,
-                             span_sequence, validate_chrome_trace,
-                             validate_jsonl_row)
+from repro.obs.trace import (HOST_PID, RingBuffer, TraceEvent, TracedLock,
+                             Tracer, read_jsonl, span_sequence,
+                             validate_chrome_trace, validate_jsonl_row)
 
 __all__ = [
     "DEFAULT_EDGES",
+    "HOST_PID",
     "CalibratedBackendSpec",
     "CalibrationMonitor",
     "Histogram",
@@ -42,6 +51,7 @@ __all__ = [
     "RingBuffer",
     "TraceEvent",
     "TraceReplay",
+    "TracedLock",
     "Tracer",
     "attribute_overhead",
     "calibrate",

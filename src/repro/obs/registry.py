@@ -2,11 +2,10 @@
 
 The registry is the numeric side of `repro.obs`: where the tracer
 records *events*, the registry records *state over time* — queue depth,
-backlog cost, busy workers, open/pending allocations, offload rate, and
-predictor absolute-residual calibration — one row per
-`LifecycleStepper.step`, into a bounded sample buffer.  `timeseries()`
-pivots the rows into parallel arrays benchmarks can dump next to their
-`BENCH_*.json` (see `benchmarks/overhead_attribution.py`).
+backlog cost, busy workers, open/pending allocations and offload rate —
+one row per `LifecycleStepper.step`, into a bounded sample buffer.
+`timeseries()` pivots the rows into parallel arrays benchmarks can dump
+next to their `BENCH_*.json` (see `benchmarks/overhead_attribution.py`).
 
 Contract for third-party policies / drivers:
 

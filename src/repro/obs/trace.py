@@ -26,8 +26,20 @@ Event model (Chrome trace-event phases):
     ``task.steal``, ``task.migrate``, ``task.requeue``, ``task.killed``,
     ``task.quarantined``, ``task.speculate``, ``task.hedge_cancel``,
     ``alloc.spawn`` / ``alloc.kill`` / ``alloc.drain-dry`` /
-    ``alloc.cancel``, ``autoalloc.submit`` / ``autoalloc.drain``, and
-    ``gp.predict_batch`` compile-shape launches.
+    ``alloc.cancel``, ``autoalloc.submit`` / ``autoalloc.drain``;
+  * scoped host spans (`Tracer.scope`) on the ``host`` process
+    (pid `HOST_PID`, one tid per thread, nesting by containment), where
+    the live service spends host time: ``svc.submit``,
+    ``predictor.predict`` / ``predictor.observe`` /
+    ``predictor.refit`` / ``predictor.condition``, ``offload.trust``,
+    ``task.complete``, ``surrogate.observe``, ``task.handoff`` (result
+    stored -> `Executor.result` returns), and the dispatch lock's
+    ``lock.wait`` / ``lock.held`` (`TracedLock`).  Each also enters a
+    `jax.profiler.TraceAnnotation` of the same name, so a profiler trace
+    shows the program's layers beside the device ops on one clock.
+    They are stamped with the live clock, carry the ``task`` id where
+    one exists, and `span_sequence` leaves them out: parity, calibration,
+    replay and attribution read the scheduling events only.
 
 Everything lands in a bounded ring buffer (oldest events drop first;
 `n_dropped` says how many), exportable as JSONL (`write_jsonl`, loadable
@@ -35,8 +47,10 @@ back with schema validation via `read_jsonl`, or streamed incrementally
 while the run is live via `stream_to`) and Chrome trace-event JSON
 (`to_chrome` / `write_chrome`, loadable in Perfetto).  Tracing is opt-in
 everywhere (`tracer=None` default) and the hot-path cost of one event is
-a tuple append into a deque (plus one buffered line write when a stream
-sink is attached).
+a tuple append into a deque under a lock, as host spans arrive from
+every thread (plus one buffered line write when a stream sink is
+attached); a host span adds two clock reads and one profiler
+annotation.
 
 Spans carry the exact model inputs calibration needs (`repro.obs.calib`
 / `repro.obs.replay` consume them): ``task.queued`` instants record the
@@ -51,12 +65,17 @@ from __future__ import annotations
 
 import json
 import math
+import threading
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 # (ts, ph, name, pid, tid, dur, args): ph in {"B","E","X","i"}; dur is
 # meaningful for "X" only; args is a small dict or None
 TraceEvent = Tuple[float, str, str, int, int, float, Optional[dict]]
+
+# the host process of `Tracer.scope` spans: apart from the scheduler (0)
+# and the allocations (alloc_id + 1)
+HOST_PID = -1
 
 _ALLOC_RANK = {None: -1, "pending": -1, "queued": 0, "running": 1,
                "draining": 2, "expired": 3}
@@ -109,10 +128,11 @@ class RingBuffer:
 class Tracer:
     """Low-overhead span/instant recorder shared by sim and live.
 
-    `clock` supplies default timestamps for instants; drivers bind their
-    injected clock (`bind_clock`) so both paths stamp the same virtual
-    seconds.  All helpers are plain tuple appends — safe under the
-    executor's dispatch lock.
+    `clock` supplies default timestamps for instants and host spans;
+    drivers bind their injected clock (`bind_clock`) so both paths stamp
+    the same virtual seconds.  The scheduling helpers run under the
+    executor's dispatch lock; host spans come from any thread, so
+    `emit` appends under a lock of its own.
     """
 
     def __init__(self, capacity: int = 65536,
@@ -125,6 +145,9 @@ class Tracer:
         self._alloc_open: Dict[int, str] = {}
         self._pid_labels: Dict[int, str] = {0: "scheduler"}
         self._sink = None                      # incremental JSONL stream
+        self._emit_lock = threading.Lock()
+        self._host_threads: List[str] = []     # host tid -> thread name
+        self._host_tls = threading.local()
 
     def bind_clock(self, clock: Callable[[], float]) -> "Tracer":
         self._clock = clock
@@ -135,9 +158,11 @@ class Tracer:
              tid: int = 0, dur: float = 0.0,
              args: Optional[dict] = None) -> None:
         ev = (float(ts), ph, name, pid, tid, float(dur), args)
-        self.buf.append(ev)
-        if self._sink is not None:
-            self._sink.write(_jsonl_line(ev))
+        line = _jsonl_line(ev) if self._sink is not None else None
+        with self._emit_lock:
+            self.buf.append(ev)
+            if line is not None and self._sink is not None:
+                self._sink.write(line)
 
     def instant(self, name: str, ts: Optional[float] = None, *,
                 pid: int = 0, tid: int = 0,
@@ -150,6 +175,29 @@ class Tracer:
              tid: int = 0, args: Optional[dict] = None) -> None:
         self.emit("X", name, start, pid=pid, tid=tid,
                   dur=max(float(end) - float(start), 0.0), args=args)
+
+    # -- host spans -------------------------------------------------------
+    def scope(self, name: str, **args) -> "_Scope":
+        """Context manager recording one ``X`` span on the calling
+        thread's host track (and a profiler annotation of that name)."""
+        return _Scope(self, name, args or None)
+
+    def host_span(self, name: str, start: float, end: float,
+                  args: Optional[dict] = None) -> None:
+        """One ``X`` span on the calling thread's host track, for an
+        interval whose start another thread saw (``task.handoff``)."""
+        self.emit("X", name, start, pid=HOST_PID, tid=self._host_tid(),
+                  dur=max(float(end) - float(start), 0.0), args=args)
+
+    def _host_tid(self) -> int:
+        tid = getattr(self._host_tls, "tid", None)
+        if tid is None:
+            with self._emit_lock:
+                tid = len(self._host_threads)
+                self._host_threads.append(threading.current_thread().name)
+                self._pid_labels[HOST_PID] = "host"
+            self._host_tls.tid = tid
+        return tid
 
     # -- task protocol ---------------------------------------------------
     def _tid(self, task_id: str) -> int:
@@ -384,7 +432,8 @@ class Tracer:
 
     # -- export ----------------------------------------------------------
     def events(self) -> List[TraceEvent]:
-        return list(self.buf)
+        with self._emit_lock:
+            return list(self.buf)
 
     @property
     def n_dropped(self) -> int:
@@ -401,6 +450,9 @@ class Tracer:
             out.append({"name": "process_name", "ph": "M", "pid": pid,
                         "tid": 0,
                         "args": {"name": self._pid_labels[pid]}})
+        for tid, thread in enumerate(list(self._host_threads)):
+            out.append({"name": "thread_name", "ph": "M", "pid": HOST_PID,
+                        "tid": tid, "args": {"name": thread}})
         # stable sort by timestamp only: same-ts events keep emission
         # order, which is the correct B/E nesting order per track (a
         # phase-priority tiebreak would split zero-length B/E pairs)
@@ -449,6 +501,106 @@ class Tracer:
                 self._sink.close()
             finally:
                 self._sink = None
+
+
+_annotation = None                             # jax.profiler.TraceAnnotation
+
+
+class _Scope:
+    """One `Tracer.scope`: the profiler annotation is entered before the
+    span's first clock read and left after its last, so the profiler's
+    span contains the tracer's.  A scope given `t0` starts at that
+    earlier reading instead; `t1` is its closing reading."""
+
+    __slots__ = ("_tracer", "_name", "_args", "_t0", "t1", "_ann")
+
+    def __init__(self, tracer: Tracer, name: str, args: Optional[dict],
+                 t0: Optional[float] = None):
+        self._tracer, self._name, self._args = tracer, name, args
+        self._t0 = t0
+
+    def __enter__(self) -> "_Scope":
+        global _annotation
+        if _annotation is None:                # lazily: no jax at import
+            from jax.profiler import TraceAnnotation
+            _annotation = TraceAnnotation
+        self._ann = _annotation(self._name)
+        self._ann.__enter__()
+        if self._t0 is None:
+            self._t0 = self._tracer._clock()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.t1 = t1 = self._tracer._clock()
+        self._ann.__exit__(*exc)
+        self._tracer.host_span(self._name, self._t0, t1, self._args)
+        return False
+
+
+class TracedLock:
+    """A `threading.RLock` that records ``lock.wait`` (asking for the
+    lock -> getting it) and ``lock.held`` (the outermost hold) on the
+    acquiring thread's host track.  It implements the RLock internals
+    `threading.Condition` uses: a ``Condition.wait`` closes the hold
+    when it releases the lock, and its re-acquire after a notify is a
+    ``lock.wait`` that opens the next hold.  A hold starts at the clock
+    reading that closed its wait, when the lock was already owned, so
+    no instrumentation falls between the two."""
+
+    __slots__ = ("_lock", "_tracer", "_depth", "_held")
+
+    def __init__(self, tracer: Tracer):
+        self._lock = threading.RLock()
+        self._tracer = tracer
+        self._depth = 0                        # the owner's recursion level
+        self._held: Optional[_Scope] = None
+
+    def acquire(self, blocking: bool = True, timeout: float = -1) -> bool:
+        if self._lock._is_owned():             # re-entry: no wait, same hold
+            self._lock.acquire()
+            self._depth += 1
+            return True
+        with self._tracer.scope("lock.wait") as wait:
+            got = self._lock.acquire(blocking, timeout)
+        if got:
+            self._depth = 1
+            self._hold(wait.t1)
+        return got
+
+    def release(self) -> None:
+        if not self._lock._is_owned():
+            raise RuntimeError("cannot release un-acquired lock")
+        self._depth -= 1
+        if self._depth == 0:
+            self._unhold()
+        self._lock.release()
+
+    __enter__ = acquire
+
+    def __exit__(self, *exc) -> None:
+        self.release()
+
+    def _is_owned(self) -> bool:
+        return self._lock._is_owned()
+
+    def _release_save(self):
+        depth, self._depth = self._depth, 0
+        self._unhold()
+        return depth, self._lock._release_save()
+
+    def _acquire_restore(self, saved) -> None:
+        depth, state = saved
+        with self._tracer.scope("lock.wait") as wait:
+            self._lock._acquire_restore(state)
+        self._depth = depth
+        self._hold(wait.t1)
+
+    def _hold(self, t0: float) -> None:
+        self._held = _Scope(self._tracer, "lock.held", None, t0).__enter__()
+
+    def _unhold(self) -> None:
+        held, self._held = self._held, None
+        held.__exit__(None, None, None)
 
 
 def _jsonl_line(ev: TraceEvent) -> str:
@@ -548,9 +700,12 @@ def span_sequence(tracer: Tracer) -> List[Tuple]:
     drivers emit the same events at the same virtual times but not
     always in the same buffer order (the live executor grants its
     initial allocation inside ``__init__``), so sequence comparison is
-    on this sorted normal form."""
+    on this sorted normal form.  Host spans (`HOST_PID`) are left out:
+    they time the live threads, which a virtual-clock driver has not."""
     out = []
-    for ts, ph, name, pid, tid, dur, args in tracer.buf:
+    for ts, ph, name, pid, tid, dur, args in tracer.events():
+        if pid == HOST_PID:
+            continue
         frozen = tuple(sorted(args.items())) if args else ()
         out.append((ts, ph, name, pid, tid, dur, frozen))
     out.sort(key=lambda e: (e[:6], repr(e[6])))

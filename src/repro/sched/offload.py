@@ -110,7 +110,8 @@ class SurrogateOffload:
         self.n_virtual_workers = n_virtual_workers
         self.condition_every = condition_every
         # optional repro.obs.Tracer: decide() emits an `offload.decide`
-        # instant per decision (set by Broker.set_tracer / the executor)
+        # instant per decision and an `offload.trust` span per trust
+        # launch (set by Broker.set_tracer / the executor)
         self.tracer = None
         # degraded state: while set, every decision is "real path" —
         # armed by a surrogate outage fault or a calib.drift alarm
@@ -211,7 +212,11 @@ class SurrogateOffload:
         theta = request_features(req)          # flattened once per request
         if theta is None or len(theta) != eng.dim():
             return False                       # not in the surrogate's space
-        sd = float(self.trust_sd([theta])[0])
+        if self.tracer is None:
+            sd = float(self.trust_sd([theta])[0])
+        else:
+            with self.tracer.scope("offload.trust", task=req.task_id):
+                sd = float(self.trust_sd([theta])[0])
         avoided = max(float(cost) - self.latency_s, 0.0)
         with self._lock:
             self._sds.append(sd)

@@ -309,6 +309,9 @@ class GPRuntimePredictor:
         self._since_refit = 0
         self._post_version = 0                 # bumped on posterior installs
         self.n_fits = 0
+        # optional repro.obs.Tracer (set by the live executor): host
+        # spans for predicts, observations, refits and conditionings
+        self.tracer = None
 
     @property
     def _post(self):
@@ -319,11 +322,18 @@ class GPRuntimePredictor:
 
     # -- RuntimePredictor -----------------------------------------------
     def observe(self, req: EvalRequest, compute_t: float) -> None:
+        tracer = self.tracer
+        if tracer is None:
+            self._observe(req, compute_t)
+            return
+        with tracer.scope("predictor.observe", task=req.task_id):
+            self._observe(req, compute_t)
+
+    def _observe(self, req: EvalRequest, compute_t: float) -> None:
         self._fallback.observe(req, compute_t)
         feats = request_features(req)
         if feats is None:
             return
-        from repro.uq import engine as uq_engine
         import numpy as np
         fit_data = cond_args = None
         with self._lock:
@@ -350,23 +360,47 @@ class GPRuntimePredictor:
         # the expensive JAX work runs OUTSIDE the lock so concurrent
         # predict()/observe() calls are never stalled behind a refit;
         # a stale-by-one posterior install is harmless (best-effort)
+        tracer = self.tracer
         if fit_data is not None:
-            new_engine = uq_engine.fit_engine(
-                fit_data[0], fit_data[1], self.backend, kind=self.kind,
-                steps=self.fit_steps)
-            with self._lock:
-                self._engine = new_engine
-                self._in_post = len(fit_data[0])
-                self._post_version += 1
-                self.n_fits += 1
+            if tracer is None:
+                self._refit(fit_data)
+            else:
+                with tracer.scope("predictor.refit", n=len(fit_data[1])):
+                    self._refit(fit_data)
         elif cond_args is not None:
-            new_engine = cond_args[0].condition(cond_args[1], cond_args[2])
-            with self._lock:
-                if self._engine is cond_args[0]:  # drop if a refit raced
-                    self._engine = new_engine
-                    self._post_version += 1
+            if tracer is None:
+                self._condition(cond_args)
+            else:
+                with tracer.scope("predictor.condition",
+                                  n=len(cond_args[2])):
+                    self._condition(cond_args)
+
+    def _refit(self, fit_data) -> None:
+        from repro.uq import engine as uq_engine
+        new_engine = uq_engine.fit_engine(
+            fit_data[0], fit_data[1], self.backend, kind=self.kind,
+            steps=self.fit_steps)
+        with self._lock:
+            self._engine = new_engine
+            self._in_post = len(fit_data[0])
+            self._post_version += 1
+            self.n_fits += 1
+
+    def _condition(self, cond_args) -> None:
+        new_engine = cond_args[0].condition(cond_args[1], cond_args[2])
+        with self._lock:
+            if self._engine is cond_args[0]:  # drop if a refit raced
+                self._engine = new_engine
+                self._post_version += 1
 
     def predict(self, req: EvalRequest) -> Optional[float]:
+        tracer = self.tracer
+        if tracer is None:
+            return self._predict(req)
+        with tracer.scope("predictor.predict", task=req.task_id, n=1):
+            return self._predict(req)
+
+    def _predict(self, req: EvalRequest) -> Optional[float]:
         feats = request_features(req)
         with self._lock:
             eng = self._engine
@@ -387,6 +421,14 @@ class GPRuntimePredictor:
         payload on re-costing.  Ineligible requests (no posterior yet,
         unflattenable or wrong-dimension payloads) take the per-model
         quantile fallback in one batch as well."""
+        tracer = self.tracer
+        if tracer is None:
+            return self._predict_many(reqs)
+        with tracer.scope("predictor.predict", n=len(reqs)):
+            return self._predict_many(reqs)
+
+    def _predict_many(self, reqs: Sequence[EvalRequest]
+                      ) -> List[Optional[float]]:
         with self._lock:
             eng = self._engine
             dim = self._dim

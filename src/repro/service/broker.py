@@ -163,6 +163,14 @@ class ServiceBroker:
         wall seconds); `block=False` raises `Backpressure` immediately.
         The admission ledger counts OPEN tasks — submitted and not yet
         terminal — so queue depth AND in-flight work both press back."""
+        tracer = self._ex.tracer
+        if tracer is None:
+            return self._admit(req, block, timeout)
+        with tracer.scope("svc.submit", task=req.task_id):
+            return self._admit(req, block, timeout)
+
+    def _admit(self, req: EvalRequest, block: bool,
+               timeout: Optional[float]) -> str:
         tenant = getattr(req, "tenant", "") or DEFAULT_TENANT
         quota = self.quotas.get(tenant)
         with self._cv:

@@ -30,19 +30,6 @@ PREDICT_BUCKETS = (64, 256, 1024)
 # the bucket discipline from this counter; it is diagnostic state only.
 predict_batch_shapes: collections.Counter = collections.Counter()
 
-# Optional repro.obs.Tracer: when set, `predict_batch` emits one
-# `gp.predict_batch` instant per launch (compile-shape visibility in the
-# same trace as the scheduling spans).  Module-level because predict is a
-# free function — there is no engine object to hang a tracer on.
-_obs_tracer = None
-
-
-def set_obs_tracer(tracer) -> None:
-    """Attach (or detach, with None) the module-wide launch tracer."""
-    global _obs_tracer
-    _obs_tracer = tracer
-
-
 def bucket_of(n: int) -> int:
     """The padded row count a chunk of `n` queries compiles at.  Raises
     (never a silent StopIteration — this is exported for external shape
@@ -304,11 +291,6 @@ def predict_batch(post: GPPosterior, x_star: jax.Array
         if pad:
             chunk = jnp.pad(chunk, ((0, pad), (0, 0)))
         predict_batch_shapes[(int(post.x.shape[0]), bucket)] += 1
-        if _obs_tracer is not None:
-            _obs_tracer.instant(
-                "gp.predict_batch",
-                args={"n": int(chunk.shape[0]) - pad, "bucket": bucket,
-                      "train_n": int(post.x.shape[0])})
         mean, var = _predict_batch(tree, post.x, post.y_mean, post.y_std,
                                    linv, post.alpha, chunk, post.kind)
         means.append(mean[:bucket - pad])
